@@ -90,6 +90,8 @@ if __name__ == "__main__":
     # jax import (XLA fixes the host device count at backend init)
     _n = int(sys.argv[1]) if len(sys.argv) > 1 and sys.argv[1].isdigit() \
         else 4
+    # a host-CPU lane: pin the CPU platform so it never takes a chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault(
         "XLA_FLAGS", f"--xla_force_host_platform_device_count={_n}")
 

@@ -44,12 +44,14 @@ from repro.analysis.lint import Finding
 from repro.core import perfmodel as pm
 from repro.core import trace as trace_lib
 
-# jaxpr primitive names that move data between devices.  `psum2` is what
-# legacy check_rep shard_map emits for lax.psum; `pbroadcast` is its
-# no-communication replication bookkeeping twin — deliberately NOT listed.
-COLLECTIVE_PRIMS = ("ppermute", "psum", "psum2", "all_gather",
+# jaxpr primitive names that move data between devices.  `psum_invariant`
+# is what shard_map's varying-manual-axes tracking emits for the psum of a
+# value that is invariant in the program's type (notably the transpose of
+# `pvary`: the weight-gradient reduction of a replicated input); `pvary`
+# itself is no-communication bookkeeping — deliberately NOT listed.
+COLLECTIVE_PRIMS = ("ppermute", "psum", "psum_invariant", "all_gather",
                     "reduce_scatter", "all_to_all")
-_KIND_NORM = {"psum2": "psum"}
+_KIND_NORM = {"psum_invariant": "psum"}
 
 # relative payload error thresholds for the priced-vs-executed join
 PAYLOAD_WARN = 0.05
@@ -61,7 +63,8 @@ _CHUNKS_RE = re.compile(r"cf chunks=(\d+)")
 @dataclasses.dataclass(frozen=True)
 class ExecutedOp:
     """One op of interest found in the traced jaxpr, with attribution."""
-    kind: str                 # normalized primitive name (psum2 -> psum)
+    kind: str                 # normalized primitive name (psum_invariant
+                              # -> psum)
     layer: str | None         # via the name-stack layer_context prefix
     direction: str            # fwd | bwd ('transpose(' in the name stack)
     region: str | None        # innermost trace.REGIONS name on the path

@@ -69,14 +69,15 @@ HOST_BASE = Machine("host-base", peak_flops=1e11, mem_bw=20e9,
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _detect_mem_capacity(default: float = 8 << 30) -> tuple[float, str]:
+def _detect_mem_capacity() -> tuple[float, str]:
     """(bytes, source) behind detect_mem_capacity / mem_capacity_source.
 
     Source precedence: the REPRO_MEM_CAPACITY env var (deterministic CI /
-    non-Linux override, plain bytes), the live device's memory_stats
-    bytes_limit, the /proc/meminfo MemAvailable share, then `default`.
-    Memoized: MemAvailable jitters call-to-call, and a calibration must
-    stay deterministic within a process.
+    non-Linux override, plain bytes), then the live backend — an
+    accelerator's memory_stats bytes_limit, or on the CPU backend the
+    /proc/meminfo MemAvailable share.  There is no default: a source that
+    cannot answer raises.  Memoized: MemAvailable jitters call-to-call,
+    and a calibration must stay deterministic within a process.
     """
     env = os.environ.get("REPRO_MEM_CAPACITY")
     if env:
@@ -87,44 +88,46 @@ def _detect_mem_capacity(default: float = 8 << 30) -> tuple[float, str]:
         except ValueError:
             print(f"calibrate: WARNING: ignoring non-numeric "
                   f"REPRO_MEM_CAPACITY={env!r}")
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        stats = None
-    if stats and stats.get("bytes_limit"):
+    dev = jax.local_devices()[0]
+    if dev.platform != "cpu":
+        # an accelerator reports its own capacity; a failure here must
+        # not turn into a host-RAM guess for a 16 GB chip
+        stats = dev.memory_stats()
+        if not stats or not stats.get("bytes_limit"):
+            raise RuntimeError(
+                f"{dev.device_kind}: memory_stats() reports no bytes_limit "
+                f"({stats!r}); pass --mem-limit BYTES or set "
+                f"REPRO_MEM_CAPACITY")
         return float(stats["bytes_limit"]), "device:memory_stats"
-    try:
-        with open("/proc/meminfo") as f:
-            for line in f:
-                if line.startswith("MemAvailable:"):
-                    kb = float(line.split()[1])
-                    return (kb * 1024 / max(jax.local_device_count(), 1),
-                            "host:/proc/meminfo")
-    except (OSError, ValueError, IndexError):
-        pass
-    return float(default), "default"
+    with open("/proc/meminfo") as f:
+        for line in f:
+            if line.startswith("MemAvailable:"):
+                kb = float(line.split()[1])
+                return (kb * 1024 / max(jax.local_device_count(), 1),
+                        "host:/proc/meminfo")
+    raise RuntimeError("/proc/meminfo has no MemAvailable line; set "
+                       "REPRO_MEM_CAPACITY")
 
 
-def detect_mem_capacity(default: float = 8 << 30) -> float:
+def detect_mem_capacity() -> float:
     """Per-device memory capacity in bytes, for Machine.mem_capacity and
     `--mem-limit auto`.
 
     A REPRO_MEM_CAPACITY env var (plain bytes) wins outright — the
     deterministic-capacity knob for CI and non-Linux hosts.  Otherwise
-    accelerators report it directly (``jax.local_devices()[0]
-    .memory_stats()['bytes_limit']``); the host CPU backend returns None
-    from memory_stats, so the documented fallback divides /proc/meminfo
-    MemAvailable among the (possibly xla_force_host_platform forced)
-    device count — all host 'devices' share one RAM, so the per-device
-    share is the honest capacity.  `default` when no source exists.
-    `mem_capacity_source()` names which source answered (recorded in
-    Calibration.meta)."""
-    return _detect_mem_capacity(default)[0]
+    accelerators report it directly (``memory_stats()['bytes_limit']``,
+    an error when absent); the host CPU backend returns None from
+    memory_stats, so there the /proc/meminfo MemAvailable share among the
+    (possibly xla_force_host_platform forced) device count answers — all
+    host 'devices' share one RAM, so the per-device share is the honest
+    capacity.  `mem_capacity_source()` names which source answered
+    (recorded in Calibration.meta)."""
+    return _detect_mem_capacity()[0]
 
 
-def mem_capacity_source(default: float = 8 << 30) -> str:
+def mem_capacity_source() -> str:
     """Which source detect_mem_capacity's answer came from."""
-    return _detect_mem_capacity(default)[1]
+    return _detect_mem_capacity()[1]
 
 
 # tests (and long-lived processes changing REPRO_MEM_CAPACITY) reset the
@@ -336,12 +339,11 @@ def _bench_collective(mesh, axis: str, op: str, nbytes: int,
         in_spec, out_spec = P(axis), P()
     else:
         raise ValueError(op)
-    # forward-only timing: replication tracking is off because a psum over
-    # one axis of a fully-replicated input defeats the legacy checker's
-    # inference (nothing is differentiated here, so it is safe).
+    # forward-only timing: VMA checking is off because a psum over one
+    # axis of a fully-replicated input is rejected by the checker (nothing
+    # is differentiated here, so it is safe).
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                           out_specs=out_spec, check_vma=False,
-                           legacy_check_rep=False))
+                           out_specs=out_spec, check_vma=False))
     return timer(fn, x)
 
 

@@ -72,7 +72,7 @@ from repro.core import trace as trace_lib
 from repro.core.spatial_conv import (ConvSharding, _conv_nhwc, _local_conv,
                                      cast_to_weight_dtype, fit_spatial_axis,
                                      spatial_conv2d)
-from repro.utils import replication_policy, same_pads, shard_map
+from repro.utils import same_pads, shard_map
 
 MODES = ("channel", "filter")
 
@@ -200,11 +200,8 @@ class CFSharding:
 
 
 def _resolve_mesh(mesh):
-    """The ambient abstract mesh, on jax versions that track one."""
-    if mesh is not None:
-        return mesh
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    return gam() if gam is not None else None
+    """`mesh`, else the ambient abstract mesh."""
+    return mesh if mesh is not None else jax.sharding.get_abstract_mesh()
 
 
 def _slice_block(v, axis_name: str, n_blocks: int, dim: int):
@@ -359,11 +356,7 @@ def cf_conv2d(x, w, *, strides=(1, 1), sharding: CFSharding, mesh=None,
                            overlap=overlap, backend=backend,
                            channel_chunks=channel_chunks)
     spec = sharding.x_spec()
-    # one repo-wide replication policy per backend (utils.replication_policy;
-    # the static auditor reports which policy each region compiled under)
-    policy = replication_policy(backend)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, P()), out_specs=spec,
-                     legacy_check_rep=policy.legacy_check_rep)(x, w)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, P()), out_specs=spec)(x, w)
 
 
 def cf_bias_add(x, b, *, sharding: CFSharding, mesh=None):

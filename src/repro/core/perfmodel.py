@@ -93,6 +93,31 @@ TPU_V5E = Machine("tpu-v5e", peak_flops=197e12, mem_bw=819e9,
                   alpha_coll=1.0e-6, beta_coll=1 / 50.0e9, wordsize=2,
                   compute_efficiency=0.55, mem_capacity=16e9)
 
+# The chips this repo prices, keyed by the exact `device_kind` the TPU
+# runtime reports (v5e reports "TPU v5 lite").
+MACHINES_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def machine_for(device, wordsize: int) -> Machine:
+    """The analytic Machine a plan on `device` is priced with, at
+    `wordsize`, the bytes per element of the step's compute dtype (4 for
+    the fp32 CNN step: the catalog's 2-byte v5e words would price half the
+    bytes it holds and moves).
+
+    On a TPU the device's `device_kind` picks it, and a kind with no entry
+    is an error, not a default.  Off-TPU (the CPU backend of tests and
+    rehearsals) TPU_V5E is the model: the plan solved there is the one the
+    chip would run."""
+    if device.platform != "tpu":
+        m = TPU_V5E
+    elif device.device_kind in MACHINES_BY_KIND:
+        m = MACHINES_BY_KIND[device.device_kind]
+    else:
+        raise ValueError(
+            f"no Machine for TPU kind {device.device_kind!r}; known: "
+            f"{sorted(MACHINES_BY_KIND)}")
+    return dataclasses.replace(m, wordsize=wordsize)
+
 
 # ---------------------------------------------------------------------------
 # communication (paper §II-B; Thakur et al. collectives)

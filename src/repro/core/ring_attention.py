@@ -165,7 +165,5 @@ def ring_attention(q, k, v, *, mesh, seq_axis: str | None, scale=None,
         softcap=softcap, unroll=unroll)
     bspec = tuple(batch_axes) or None
     spec = P(bspec, seq_axis, None, None)
-    # ppermute-only body, sharded outputs: gradient-safe without legacy
-    # replication tracking (which cannot transpose the ring scan).
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, legacy_check_rep=False)(q, k, v)
+                     out_specs=spec)(q, k, v)

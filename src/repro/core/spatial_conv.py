@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import halo as halo_lib
 from repro.core import trace as trace_lib
-from repro.utils import cdiv, replication_policy, same_pads, shard_map
+from repro.utils import cdiv, same_pads, shard_map
 
 DIMNUMS = ("NHWC", "HWIO", "NHWC")
 
@@ -121,12 +121,15 @@ def _conv_nhwc(x, w, strides, pads, backend: str = "xla",
     backend='pallas' routes through the implicit-GEMM MXU kernel
     (repro.kernels.conv2d).  That kernel computes VALID convolution with one
     stride for both spatial dims, so padding is materialized first and
-    unequal strides fall back to XLA.  Off-TPU it runs in interpret mode
+    unequal strides are an error.  Off-TPU it runs in interpret mode
     (numerics-identical, for tests and CPU smoke runs).  `interior_first`
     asks the Pallas kernel for its §IV-A schedule (boundary row blocks
     visited last); the XLA route ignores it.
     """
-    if backend == "pallas" and strides[0] == strides[1]:
+    if backend == "pallas":
+        if strides[0] != strides[1]:
+            raise ValueError(f"backend='pallas' needs equal strides, got "
+                             f"{tuple(strides)}")
         from repro.kernels.conv2d import conv2d as pallas_conv2d
         xp = jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0)))
         return pallas_conv2d(xp, w, stride=strides[0],
@@ -282,11 +285,7 @@ def spatial_conv2d(x, w, *, strides=(1, 1), sharding: ConvSharding,
                            mesh_shape=mesh_shape, overlap=overlap,
                            backend=backend)
     spec = sharding.x_spec()
-    # one repo-wide replication policy per backend (utils.replication_policy;
-    # the static auditor reports which policy each region compiled under)
-    policy = replication_policy(backend)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, P()), out_specs=spec,
-                     legacy_check_rep=policy.legacy_check_rep)(x, w)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, P()), out_specs=spec)(x, w)
 
 
 # ---------------------------------------------------------------------------

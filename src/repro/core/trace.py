@@ -105,13 +105,10 @@ def annotate(region: str):
     XLA profiles decode to plan terms; the
     ``jax.profiler.TraceAnnotation`` additionally marks host-side
     profiler timelines when a profiler session is active (it is a no-op
-    otherwise, and absent on backends without it).
+    otherwise).
     """
-    ta = getattr(jax.profiler, "TraceAnnotation", None)
-    with contextlib.ExitStack() as st:
-        st.enter_context(jax.named_scope(region))
-        if ta is not None:
-            st.enter_context(ta(qualified(region)))
+    with jax.named_scope(region), \
+            jax.profiler.TraceAnnotation(qualified(region)):
         yield
 
 

@@ -24,7 +24,9 @@ import sys
 
 # device count MUST precede every other import (jax locks it on first
 # init): the pod-scale lowering wants 512 host devices, the --audit lane
-# wants the small bench mesh (2x2, matching the CI bench lane).
+# wants the small bench mesh (2x2, matching the CI bench lane).  This is a
+# host-lowering tool: it pins the CPU platform so it never takes a chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=4"
     if "--audit" in sys.argv else
@@ -316,7 +318,6 @@ def _probe_extrapolate(arch, shape, mesh, variant, n_layers):
     linearly:  total(L) = C2 + (C4 - C2)/2 * (L - 2).  The marginal slope
     is exactly one layer's flops/bytes/collective traffic (incl. its FSDP
     gathers and optimizer update); the intercept holds embed/logits/loss."""
-    import dataclasses
     cfg0 = registry.get(arch)
     out = {}
     for d in (2, 4):
@@ -434,7 +435,6 @@ def run_audit(workload_names, out_path: str, hlo: bool = True,
     from repro.analysis import workloads as WL
     from repro.core import perfmodel as pm
     from repro.launch.mesh import make_mesh
-    from repro.utils import replication_policy
 
     ndev = jax.device_count()
     data = max(1, ndev // 2)
@@ -446,12 +446,6 @@ def run_audit(workload_names, out_path: str, hlo: bool = True,
         "backend": jax.default_backend(),
         "mesh": dict(mesh.shape),
         "search": search,
-        # which shard_map replication policy each backend's regions
-        # compile under (the one utils.replication_policy source of truth)
-        "replication_policy": {
-            b: {**dataclasses.asdict(replication_policy(b)),
-                "legacy_check_rep": replication_policy(b).legacy_check_rep}
-            for b in ("xla", "pallas")},
         "workloads": {},
     }
     n_errors = 0

@@ -24,11 +24,9 @@ MODEL_AXIS = "model"            # the paper's fine-grained axis
 
 def _mk(shape, axes, devices=None):
     kw = {"devices": devices} if devices is not None else {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:       # pre-AxisType jax: Auto is the only behavior
-        return jax.make_mesh(shape, axes, **kw)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes), **kw)
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,12 +4,17 @@
       --steps 50 --batch 8 --seq 64 --ckpt-dir /tmp/ck [--data 2 --model 2]
 
 Runs the resilient loop (checkpoint/restart, straggler monitor) around the
-jit'd train step.  On this CPU container use --smoke (reduced config); the
-full configs are for the TPU pods the dry-run targets.  `--arch mesh1k/
-mesh2k/resnet50 --smoke` trains the paper's CNN workloads under hybrid
-sample x spatial parallelism; add `--strategy auto` to run the paper's §V-C
-strategy optimizer at startup and execute its per-layer distribution plan
-(with automatic inter-layer resharding) instead of the uniform default.
+jit'd train step, on whatever devices JAX finds; the startup line names
+them.  On a TPU the full configs train as they are: `python chip_smoke.py`
+runs mesh1k at full width on one v5e chip (and `--chips 4` across a 2x2
+host).  On a CPU host use --smoke (reduced config) with
+`JAX_PLATFORMS=cpu`.  JAX's persistent compilation cache is on
+(launch.compile_cache: `$JAX_COMPILATION_CACHE_DIR`, else
+`<repo>/.jax_cache`).  `--arch mesh1k/mesh2k/resnet50` trains the
+paper's CNN workloads under hybrid sample x spatial parallelism; add
+`--strategy auto` to run the paper's §V-C strategy optimizer at startup
+and execute its per-layer distribution plan (with automatic inter-layer
+resharding) instead of the uniform default.
 The solved plan may mix sample, spatial and channel/filter (§III-D) layers
 — including H/W split over *products* of mesh axes (core.halo) and
 CF x spatial compositions whose halo exchange and CF collective share one
@@ -21,17 +26,18 @@ sample/spatial for A/B comparison.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import logging
 import time
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import registry
 from repro.data import pipeline
+from repro.launch import compile_cache
 from repro.launch import shardings as SH
 from repro.launch.mesh import batch_axes, elastic_factorization, make_mesh
 from repro.optim.optimizer import adamw, sgd, warmup_cosine
@@ -65,9 +71,14 @@ def build_cnn_plan(args, arch, cfg, mesh, ba):
     microbenchmarked on the live backend and written there.  With
     --mem-limit the solve is memory-aware: min-time subject to every
     layer's resident set (and the network peak) fitting the per-device
-    capacity — the paper's §VI Table-2 'unreachable workloads' lever."""
+    capacity — the paper's §VI Table-2 'unreachable workloads' lever.
+
+    The analytic Machine comes from the mesh's device kind
+    (perfmodel.machine_for); off-TPU it is TPU_V5E, the rehearsal model.
+    Only the mesh's structure is used: it may be a mesh of described
+    devices (tests/rehearse_v5e.py)."""
     from repro.core import plan as plan_lib
-    from repro.core.perfmodel import TPU_V5E
+    from repro.core.perfmodel import machine_for
     from repro.core.spatial_conv import ConvSharding
     from repro.utils import human_bytes
     if arch == "resnet50":
@@ -78,7 +89,9 @@ def build_cnn_plan(args, arch, cfg, mesh, ba):
         from repro.models.cnn import meshnet as M
         specs = M.layer_specs(cfg, args.batch)
         graph = None
-    machine, table, calib_fp = TPU_V5E, None, None
+    # the step's own words: 4-byte fp32 (launch.train trains CNNs in FP32)
+    machine = machine_for(mesh.devices.flat[0], FP32.compute_bytes)
+    table, calib_fp = None, None
     if args.calibrate and args.strategy != "auto":
         # measured costs only feed the solver — don't spend minutes
         # microbenchmarking for a plan that ignores them
@@ -147,23 +160,33 @@ def on_mesh(tree, mesh):
     scalar optimizer counters opt.init leaves uncommitted on one device —
     is replicated.  A restore template must be *fully* committed to its
     mesh or reshard-on-restore would re-commit stray leaves to a single
-    device and the jitted step would see mixed device sets."""
-    devs = set(np.asarray(mesh.devices).ravel().tolist())
+    device and the jitted step would see mixed device sets.  A leaf on
+    the mesh's devices but not under a NamedSharding of it (an uncommitted
+    scalar on a 1x1 mesh) is re-placed too: the step's outputs carry the
+    mesh in their type, so it would recompile at step 1."""
     def fix(x):
         sh = getattr(x, "sharding", None)
-        if sh is not None and set(sh.device_set) == devs:
+        if isinstance(sh, NamedSharding) and sh.mesh == mesh:
             return x
         return jax.device_put(x, NamedSharding(mesh, P()))
     return jax.tree.map(fix, tree)
 
 
+def shardings_of(tree):
+    return jax.tree.map(lambda x: x.sharding, tree)
+
+
 def build(args, mesh):
+    """Model, plan, loss, optimizer and batch source for `args` on `mesh`.
+    Returns params on the host; train_state places them."""
     arch = registry.canon(args.arch)
     ba = batch_axes(mesh)
     extras = {"arch": arch, "plan": None, "specs": None, "layer_names": None,
               "calib_fp": None}
     if arch in registry.CNN_ARCHS:
         cfg = registry.get(arch, smoke=args.smoke)
+        if args.bn_scope:
+            cfg = dataclasses.replace(cfg, bn_scope=args.bn_scope)
         plan, specs, calib_fp = build_cnn_plan(args, arch, cfg, mesh, ba)
         extras.update(plan=plan, specs=specs, calib_fp=calib_fp)
         if arch == "resnet50":
@@ -183,13 +206,7 @@ def build(args, mesh):
         first = specs[0]
         im_spec = plan.input_spec(first.name, first.h, first.w, first.k,
                                   first.s, mesh)
-
-        def put(b):
-            out = {}
-            for k, v in b.items():
-                spec = im_spec if k == "image" else P(ba)
-                out[k] = jax.device_put(v, NamedSharding(mesh, spec))
-            return out
+        batch_spec = lambda k: im_spec if k == "image" else P(ba)
     else:
         from repro.models.lm import transformer as T
         from repro.models.lm.modules import ShardCtx
@@ -208,6 +225,9 @@ def build(args, mesh):
         if args.mem_limit:
             logging.warning("--mem-limit covers the CNN archs only; "
                             "ignored for %s", arch)
+        if args.bn_scope:
+            logging.warning("--bn-scope covers the CNN archs only; "
+                            "ignored for %s", arch)
         cfg = registry.get(arch, smoke=args.smoke)
         ctx = ShardCtx(mesh=mesh, seq_axis="model", batch_axes=ba)
         loss = functools.partial(T.loss_fn, cfg=cfg, ctx=ctx,
@@ -217,19 +237,37 @@ def build(args, mesh):
         prec = BF16 if args.bf16 else FP32
         mk = lambda s: pipeline.synthetic_lm_batch(
             s, args.batch, args.seq, cfg.vocab)
+        batch_spec = lambda k: P(ba, "model")
 
-        def put(b):
-            return {k: jax.device_put(v, NamedSharding(mesh, P(ba, "model")))
-                    for k, v in b.items()}
+    extras["batch_spec"] = batch_spec
 
-    pspecs = SH.fsdp_tree_specs(params, mesh)
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        params, pspecs)
+    def put(b):
+        return {k: jax.device_put(v, NamedSharding(mesh, batch_spec(k)))
+                for k, v in b.items()}
     return cfg, params, opt, loss, mk, put, prec, extras
 
 
-def main():
+def train_state(params, opt, mesh):
+    """(params, optimizer state, error-feedback state) committed to `mesh`:
+    params under their FSDP specs, the optimizer state as `opt.init` lays
+    it out beside them, stray leaves replicated (on_mesh)."""
+    params = jax.tree.map(
+        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+        params, SH.fsdp_tree_specs(params, mesh))
+    return on_mesh((params, opt.init(params), None), mesh)
+
+
+def train_step(args, opt, loss, prec, mesh, state):
+    """The jitted step, its new state pinned to `state`'s shardings (the
+    leaves may be arrays or ShapeDtypeStructs)."""
+    return make_train_step(
+        lambda p, b: loss(p, b), opt, mesh,
+        TrainStepConfig(grad_accum=args.grad_accum, precision=prec,
+                        pod_compression=args.pod_compression),
+        state_shardings=shardings_of(state))
+
+
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mesh1k",
                     help="architecture id (registry); defaults to the "
@@ -279,6 +317,13 @@ def main():
                          "'auto' (the bare-flag default) detects the live "
                          "device capacity; an integer sets a synthetic "
                          "limit in bytes — CNN archs only")
+    ap.add_argument("--bn-scope", default=None,
+                    choices=["local", "spatial", "global"],
+                    help="CNN batch-norm statistics scope (core."
+                         "spatial_norm; default: the arch config's, "
+                         "'local' per shard for the paper's models).  "
+                         "'global' normalizes over the whole global batch, "
+                         "so any mesh computes the 1-device function")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -335,7 +380,14 @@ def main():
                     help="check loss/grad_norm for NaN/inf every step and "
                          "fail fast naming the first offending layer "
                          "(train.metrics.debug_nan_check)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    """Train from command-line style `argv` (default: sys.argv[1:])."""
+    ap = parser()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
     try:
         from repro.core.strategy import parse_search
         parse_search(args.search)
@@ -344,22 +396,21 @@ def main():
 
     mesh = make_mesh(data=args.data, model=args.model, pod=args.pod)
     cfg, params, opt, loss, mk, put, prec, extras = build(args, mesh)
+    dev = mesh.devices.flat[0]
     print(f"arch={cfg.name} params={human_count(tree_num_params(params))} "
-          f"mesh={dict(mesh.shape)}")
+          f"mesh={dict(mesh.shape)} device={dev.platform}:{dev.device_kind} "
+          f"x{mesh.devices.size}")
 
     if args.audit:
         audit_gate(args, cfg, mesh, extras)
 
+    state = train_state(params, opt, mesh)
     if args.profile:
-        profile(args, cfg, params, mk, put, mesh, extras)
+        profile(args, cfg, state[0], mk, put, mesh, extras)
         return
 
-    tstep = make_train_step(
-        lambda p, b: loss(p, b), opt, mesh,
-        TrainStepConfig(grad_accum=args.grad_accum, precision=prec,
-                        pod_compression=args.pod_compression))
     ck = CheckpointManager(args.ckpt_dir, keep=3, async_save=True)
-    state = on_mesh((params, opt.init(params), None), mesh)
+    tstep = train_step(args, opt, loss, prec, mesh, state)
     start = 0
     restored, manifest = ck.restore(state) if ck.latest_step() else (None,
                                                                      None)
@@ -390,16 +441,19 @@ def main():
     def make_step():
         def run(state, step):
             p, o, ef = state
+            t_step = time.perf_counter()
             b = ctx["put"](pf.get(step))
+            ctx["batch_shardings"] = {k: v.sharding for k, v in b.items()}
             p, o, ef, m = ctx["tstep"](p, o, ef, b)
-            losses.append(float(m["loss"]))
+            host = {k: float(v) for k, v in m.items()}    # syncs the step
+            wall = time.perf_counter() - t_step
+            losses.append(host["loss"])
             if args.debug_nans:
-                host = {k: float(v) for k, v in m.items()
-                        if k in ("loss", "grad_norm")}
                 debug_nan_check(step, host, p, ctx["layer_names"])
             dt = (time.time() - t0) / (len(losses) or 1)
             mlog.log_step(step, losses[-1], step_time_s=dt,
                           samples_per_s=args.batch / dt if dt else None,
+                          grad_norm=host["grad_norm"], wall_s=wall,
                           echo=step % args.log_every == 0)
             return (p, o, ef), m
         return run
@@ -420,15 +474,12 @@ def main():
                              devices=list(survivors))
         cfg2, params2, opt2, loss2, _, put2, prec2, extras2 = \
             build(args, new_mesh)
-        ctx["tstep"] = make_train_step(
-            lambda p, b: loss2(p, b), opt2, new_mesh,
-            TrainStepConfig(grad_accum=args.grad_accum, precision=prec2,
-                            pod_compression=args.pod_compression))
+        state2 = train_state(params2, opt2, new_mesh)
+        ctx["tstep"] = train_step(args, opt2, loss2, prec2, new_mesh, state2)
         ctx["put"] = put2
         ctx["layer_names"] = extras2["layer_names"]
         ctx["plan_spec"] = plan_record(args, cfg2, extras2, new_mesh)
-        return make_step, on_mesh((params2, opt2.init(params2), None),
-                                  new_mesh)
+        return make_step, state2
 
     loop = ResilientLoop(ckpt=ck, make_step=make_step,
                          ckpt_every=args.ckpt_every,
@@ -449,6 +500,8 @@ def main():
     mlog.close()
     print(f"done at step {step}; final loss {losses[-1]:.4f}; "
           f"straggler stats {mon.stats}")
+    return {"step": step, "losses": losses, "state": state, "mesh": mesh,
+            "batch_shardings": ctx.get("batch_shardings", {})}
 
 
 def audit_gate(args, cfg, mesh, extras):

@@ -278,9 +278,7 @@ def ssm_init(key, cfg: LMConfig, dtype):
 
 def _match_vma(x, like):
     """Mark x varying over the same manual axes as `like` (shard_map VMA)."""
-    typeof = getattr(jax, "typeof", None)   # absent pre-0.6 (no VMA there)
-    vma = getattr(typeof(like), "vma", frozenset()) if typeof else frozenset()
-    return pcast_varying(x, tuple(vma))
+    return pcast_varying(x, tuple(jax.typeof(like).vma))
 
 
 def _ssd_chunked(xdt, la, B, C, chunk: int, h0=None):

@@ -36,9 +36,7 @@ def _ring(x, axis, axis_size):
 
 
 def _vma(x, like):
-    typeof = getattr(jax, "typeof", None)   # absent pre-0.6 (no VMA there)
-    vma = getattr(typeof(like), "vma", frozenset()) if typeof else frozenset()
-    return pcast_varying(x, tuple(vma))
+    return pcast_varying(x, tuple(jax.typeof(like).vma))
 
 
 def _lookup_local(tokens, table, *, axis, axis_size, unroll):
@@ -72,13 +70,10 @@ def embed_lookup(table, cfg: LMConfig, tokens, ctx, seq_axis="model"):
     fn = functools.partial(_lookup_local, axis=seq_axis, axis_size=n,
                            unroll=ctx.unroll)
     bspec = tuple(ctx.batch_axes) or None
-    # ppermute-only body, sharded outputs: gradient-safe without legacy
-    # replication tracking (which cannot transpose the ring scan).
     return shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, seq_axis), P(seq_axis, None)),
-        out_specs=P(bspec, seq_axis, None),
-        legacy_check_rep=False)(tokens, table)
+        out_specs=P(bspec, seq_axis, None))(tokens, table)
 
 
 def _logits_chunk(x, tbl, lo, *, scale, softcap, v_real, vshard):

@@ -8,7 +8,9 @@ may *leave* — the device set shrinks.  The driver's contract:
     repro.checkpoint), recording the solved plan spec in the manifest;
   * on a step fault: roll back to the latest committed checkpoint, rebuild
     the step function (fresh compilation), continue; give up after
-    `max_failures` *consecutive* failures;
+    `max_failures` *consecutive* failures.  A fault before any checkpoint
+    is committed re-raises at once: the jitted step donates its input
+    state, so there is nothing intact to retry from;
   * on device loss (`DeviceLoss`, carrying the surviving devices): hand
     the survivors to the `remesh` callback, which rebuilds the mesh from
     them, re-solves the plan on the shrunk mesh under the same mem_limit
@@ -181,6 +183,11 @@ class ResilientLoop:
                 if failures > self.max_failures:
                     raise
                 self.ckpt.wait()
+                if self.ckpt.latest_step() is None:
+                    # no checkpoint: the state in hand may be the step's
+                    # donated (deleted) input, and a retry on it would
+                    # bury this error under "Array has been deleted"
+                    raise
                 state, step = self._rollback(state, start_step)
                 step_fn = self.make_step()          # fresh compile
         self.ckpt.wait()

@@ -35,17 +35,28 @@ class TrainStepConfig:
 
 
 def make_train_step(loss_fn: Callable, opt: Optimizer, mesh,
-                    cfg: TrainStepConfig = TrainStepConfig()):
-    """loss_fn(params, batch) -> scalar loss (params in compute dtype).
+                    cfg: TrainStepConfig = TrainStepConfig(),
+                    state_shardings=None):
+    """loss_fn(params, batch) -> scalar loss (params in compute dtype),
+    traced under the precision policy's conv/dot precision.
 
     Returns step(params, opt_state, ef_state, batch) ->
             (params, opt_state, ef_state, metrics).
+
+    `state_shardings` — the shardings of (params, opt_state, ...) as
+    placed — pins the updated params and optimizer state to their input
+    layout.  Without it XLA
+    picks output shardings from the step's own collectives (a CF layer's
+    gradient leaves model-sharded), so the next call sees new input
+    shardings and compiles the step again, and the donated buffers cannot
+    be reused in place.
     """
     lfn = jax.checkpoint(loss_fn) if cfg.remat else loss_fn
 
     def fwd_bwd(params, batch):
         cparams = cfg.precision.cast_compute(params)
-        loss, grads = jax.value_and_grad(lfn)(cparams, batch)
+        with cfg.precision.scope():
+            loss, grads = jax.value_and_grad(lfn)(cparams, batch)
         # master-dtype grads for the optimizer
         grads = jax.tree.map(
             lambda g, p: g.astype(p.dtype), grads, params)
@@ -77,6 +88,9 @@ def make_train_step(loss_fn: Callable, opt: Optimizer, mesh,
                 error_feedback=ef_state)
 
         new_params, new_opt = opt.update(grads, opt_state, params)
+        if state_shardings is not None:
+            new_params, new_opt = jax.lax.with_sharding_constraint(
+                (new_params, new_opt), tuple(state_shardings[:2]))
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                              for g in jax.tree.leaves(grads)))
         return new_params, new_opt, ef_state, {"loss": loss,

@@ -1,8 +1,8 @@
 """Shared small utilities."""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import math
 from typing import Any, Sequence
 
 import jax
@@ -11,82 +11,23 @@ import numpy as np
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None, legacy_check_rep=None):
-    """`jax.shard_map` with a fallback to the pre-0.6 experimental API.
-
-    New-API kwargs translate: `axis_names` (manual axes) becomes the legacy
-    `auto` complement; `check_vma` maps onto `check_rep`.
-
-    `legacy_check_rep` overrides check_rep on the legacy path only: legacy
-    replication tracking cannot transpose a scan inside shard_map (cotangent
-    carries have unknown rep), so bodies that are gradient-safe without
-    tracking — pure ppermute rings with no psum and no replicated outputs —
-    pass False here.  Bodies with psum/replicated outputs must keep tracking
-    on: with check_rep=False their legacy transpose over-accumulates by the
-    axis size, corrupting gradients.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    # axis_names (partial-manual) is intentionally dropped: legacy `auto=`
-    # partial-manual trips an SPMD-partitioner check in this XLA build, and
-    # our partial-manual callers only run elementwise math + collectives on
-    # the manual axes, which is equally valid fully manual.
-    check_rep = legacy_check_rep if legacy_check_rep is not None \
-        else (check_vma if check_vma is not None else True)
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_rep)
-
-
-@dataclasses.dataclass(frozen=True)
-class RepPolicy:
-    """The shard_map replication-checking policy one backend compiles
-    under, with the reason recorded — the single source call sites quote
-    instead of choosing `legacy_check_rep` ad hoc (the static-analysis
-    auditor reports which policy each region compiled under)."""
-    backend: str
-    check_rep: bool
-    reason: str
-
-    @property
-    def legacy_check_rep(self) -> bool | None:
-        """The value to pass through `shard_map(..., legacy_check_rep=)`:
-        None keeps the legacy default (tracking on); False disables it."""
-        return None if self.check_rep else False
-
-
-REP_POLICIES = {
-    "xla": RepPolicy(
-        "xla", check_rep=True,
-        reason="legacy replication tracking stays on: bodies psum/return "
-               "replicated outputs, and an untracked transpose would "
-               "over-accumulate their cotangents by the axis size"),
-    "pallas": RepPolicy(
-        "pallas", check_rep=False,
-        reason="legacy tracking cannot transpose pallas_call; the Pallas "
-               "bodies are forward-only ppermute rings with no psum, which "
-               "are gradient-safe without tracking"),
-}
-
-
-def replication_policy(backend: str) -> RepPolicy:
-    """The one shard_map check_rep policy for `backend` (default: xla)."""
-    return REP_POLICIES.get(backend, REP_POLICIES["xla"])
+              check_vma=None):
+    """`jax.shard_map`, passing `axis_names` (the manual axes) and
+    `check_vma` only when given so jax's own defaults apply otherwise."""
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pcast_varying(x, axes):
-    """`lax.pcast(..., to='varying')` under VMA-tracking jax; identity on
-    pre-VMA jax, where there is no varying/invariant distinction to mark."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None or not axes:
+    """`lax.pcast(..., to='varying')` over `axes`; identity when empty."""
+    if not axes:
         return x
-    return pcast(x, axes, to="varying")
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def cdiv(a: int, b: int) -> int:
@@ -241,12 +182,28 @@ class Precision:
     param_dtype: Any = jnp.float32     # master weights
     compute_dtype: Any = jnp.bfloat16  # activations / matmul inputs
     accum_dtype: Any = jnp.float32     # softmax / loss / BN stats
+    # conv/dot precision the step is traced under (None: JAX's default,
+    # which on a TPU runs an fp32 conv as one bf16 MXU pass)
+    matmul: str | None = None
 
     def cast_compute(self, tree):
         return jax.tree.map(
             lambda x: x.astype(self.compute_dtype)
             if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
 
+    @property
+    def compute_bytes(self) -> int:
+        """Bytes per element of the compute dtype (the perf model's word)."""
+        return jnp.dtype(self.compute_dtype).itemsize
 
-FP32 = Precision(jnp.float32, jnp.float32, jnp.float32)
+    def scope(self):
+        """Context in which traced convs and dots take `matmul`."""
+        if self.matmul is None:
+            return contextlib.nullcontext()
+        return jax.default_matmul_precision(self.matmul)
+
+
+# fp32 means fp32 through the MXU too: on a TPU v5e the default precision
+# moved mesh1k's first-step loss by 2.6e-3 relative against `highest`
+FP32 = Precision(jnp.float32, jnp.float32, jnp.float32, matmul="highest")
 BF16 = Precision(jnp.float32, jnp.bfloat16, jnp.float32)

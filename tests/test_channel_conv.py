@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.core.channel_conv import (CFSharding, cf_batch_norm, cf_bias_add,
                                      cf_conv2d)
@@ -35,7 +36,9 @@ def test_cfsharding_surface():
     assert not sh.is_spatial
     assert sh.h_axis is None and sh.w_axis is None
     assert sh.fit(32, 32, 3, 1, None) == sh          # geometry fit: no-op
-    assert tuple(sh.x_spec()) == (("data",), None, None, "model")
+    # PartitionSpec equality normalizes a 1-tuple entry to its axis name
+    assert sh.x_spec() == P(("data",), None, None, "model")
+    assert sh.x_spec() == P("data", None, None, "model")
     assert sh.fits_channels(8, 16, {"model": 2})
     assert not sh.fits_channels(5, 16, {"model": 2})
     assert not sh.fits_channels(8, 7, {"model": 2})
@@ -138,7 +141,7 @@ def test_cfsharding_spatial_composition_surface():
     sh = CFSharding(batch_axes=("pod",), cf_axis="model",
                     h_axis=("data", "x"))
     assert sh.is_spatial and sh.h_axes == ("data", "x")
-    assert tuple(sh.x_spec()) == (("pod",), ("data", "x"), None, "model")
+    assert sh.x_spec() == P("pod", ("data", "x"), None, "model")
     # geometry fit drops an unfit product split (shard < kernel)
     fitted = sh.fit(4, 4, 3, 1, _FakeMesh({"data": 2, "x": 2,
                                            "model": 2, "pod": 2}))

@@ -233,6 +233,31 @@ def test_persistent_failure_gives_up():
         shutil.rmtree(d)
 
 
+def test_fault_before_first_checkpoint_reraises():
+    """A step fault with no committed checkpoint is not retried: the
+    original exception surfaces (after its fault event), never a retry on
+    the step's donated input state."""
+    d = tempfile.mkdtemp()
+    try:
+        _, loop = _np_loop(d)
+        mpath = os.path.join(d, "m.jsonl")
+        loop.metrics = MetricsLogger(mpath, echo=False)
+        calls = []
+
+        def inject(step):
+            calls.append(step)
+            if step == 2:
+                raise ValueError("compile failed")
+        with pytest.raises(ValueError, match="compile failed"):
+            loop.run({"x": np.float32(1.0)}, 0, 12, inject_failure=inject)
+        loop.metrics.close()
+        assert calls == [0, 1, 2]                  # no retry of step 2
+        kinds = [json.loads(ln)["kind"] for ln in open(mpath)]
+        assert "fault" in kinds and "rollback" not in kinds
+    finally:
+        shutil.rmtree(d)
+
+
 # ------------------------------------------------------------------ chaos --
 def test_chaos_parse_and_fire_once():
     h = chaos.parse("raise@2")

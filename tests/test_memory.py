@@ -283,13 +283,53 @@ def test_plan_line_infeasible_limit_raises():
 
 # --------------------------------------------------- capacity detection --
 def test_detect_mem_capacity_host_fallback():
-    """On this CPU container memory_stats() is None, so the /proc/meminfo
-    share (or the default) answers — finite, positive, and memoized so
-    calibrations stay deterministic within a process."""
+    """On the CPU backend memory_stats() is None, so the /proc/meminfo
+    share answers — finite, positive, and memoized so calibrations stay
+    deterministic within a process."""
     from repro.core.calibrate import detect_mem_capacity
     cap = detect_mem_capacity()
     assert math.isfinite(cap) and cap > 0
     assert detect_mem_capacity() == cap
+
+
+class _FakeChip:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+@pytest.mark.parametrize("stats, expect", [
+    ({"bytes_limit": 17_000_000_000, "peak_bytes_in_use": 0},
+     17_000_000_000.0),
+    ({"peak_bytes_in_use": 0}, RuntimeError),
+    (None, RuntimeError),
+    (OSError("runtime down"), OSError),
+])
+def test_detect_mem_capacity_accelerator_never_guesses(monkeypatch, stats,
+                                                       expect):
+    """An accelerator's capacity comes from its memory_stats bytes_limit
+    or not at all: a failing or limit-less report raises instead of
+    falling back to a host-RAM share or a default."""
+    from repro.core import calibrate as cal
+    monkeypatch.delenv("REPRO_MEM_CAPACITY", raising=False)
+    monkeypatch.setattr(cal.jax, "local_devices",
+                        lambda: [_FakeChip(stats)])
+    cal.detect_mem_capacity.cache_clear()
+    try:
+        if isinstance(expect, float):
+            assert cal.detect_mem_capacity() == expect
+            assert cal.mem_capacity_source() == "device:memory_stats"
+        else:
+            with pytest.raises(expect):
+                cal.detect_mem_capacity()
+    finally:
+        cal.detect_mem_capacity.cache_clear()
 
 
 def test_calibration_roundtrips_mem_capacity():

@@ -118,6 +118,23 @@ def test_cf_collective_words_at_submesh_sizes():
     assert pm.cf_mode_for(layer, pure, ms) == "filter"   # F=2C at s=1
 
 
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_machine_for_device_kind():
+    """A TPU's Machine is looked up by its exact device_kind (an unknown
+    kind is an error, never a default); off-TPU the analytic v5e model
+    prices the rehearsal plan.  Either way the words are the step's."""
+    fp32 = dataclasses.replace(pm.TPU_V5E, wordsize=4)
+    assert pm.machine_for(_Dev("tpu", "TPU v5 lite"), 4) == fp32
+    assert pm.machine_for(_Dev("cpu", "cpu"), 4) == fp32
+    assert pm.machine_for(_Dev("cpu", "cpu"), 2) == pm.TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        pm.machine_for(_Dev("tpu", "TPU v4"), 4)
+
+
 def test_candidates_valid():
     layer = pm.ConvLayer("c", n=6, c=18, h=96, w=96, f=64, k=3, s=2)
     ms = {"data": 3, "model": 2}
