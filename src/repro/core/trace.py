@@ -5,19 +5,27 @@ runtime *attribute* where a measured step actually spends its time, so the
 model-vs-measured comparison decomposes per layer and per cost term instead
 of being one opaque end-to-end ratio.
 
-Two mechanisms:
+Three mechanisms:
 
   * **Named-region annotation** — `annotate(region)` wraps a stretch of
-    traced code in ``jax.named_scope`` (the name lands in the compiled
-    HLO's ``op_name`` metadata, so XLA profiles and `compiled.as_text()`
-    are decodable) plus ``jax.profiler.TraceAnnotation`` (host-side
-    profiler timelines).  `layer_context(name)` pushes the current layer
-    name so every region inside an execution path is keyed by the layer
-    that ran it — the paths thread it through halo exchange
-    (core.halo), interior/boundary conv (core.spatial_conv), the CF
-    collectives and BN psums (core.channel_conv) and §III-C reshard
-    points (core.plan).  Annotation is identity on values: it never
-    changes numerics or op order, only metadata.
+    traced code in ``jax.named_scope``, so the name lands in the compiled
+    HLO's ``op_name`` metadata and device profiles and
+    `compiled.as_text()` decode to plan terms.  `layer_context(name)`
+    opens the layer's own scope around it, so every region inside an
+    execution path is keyed by the layer that ran it
+    (``conv3_1/halo_exchange``) — the paths thread it through halo
+    exchange (core.halo), interior/boundary conv (core.spatial_conv), the
+    CF collectives and BN psums (core.channel_conv) and §III-C reshard
+    points (core.plan).  Both act while the step is traced, never while it
+    runs, and are identity on values: they change metadata only.
+
+  * **Spans that close when the device is ready** — `span_until_ready(
+    name, fn, *args)` opens a ``jax.profiler.TraceAnnotation`` on the
+    caller's thread, runs `fn`, and leaves the span to one daemon thread
+    that closes it once every array `fn` returned is ready on its device.
+    The trainer's step (launch.train.run_step) times its host->device
+    input copy (``train.h2d``) this way, on the profiler's clock beside the
+    device planes, without blocking the step loop.
 
   * **Segmented re-execution profiling** — `trace_plan(plan, params,
     batch)` AOT-compiles each plan layer's forward and forward+backward
@@ -39,11 +47,16 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import json
+import logging
+import queue
+import threading
 from typing import Mapping
 
 import jax
 
 SCHEMA = "repro/step_trace@1"
+
+log = logging.getLogger(__name__)
 
 # ---------------------------------------------------------------------------
 # named-region annotation
@@ -65,35 +78,15 @@ REGIONS = (
     "reshard",            # §III-C reshard points (core.plan)
 )
 
-_LAYER_STACK: list[str] = []
-
-
-def current_layer() -> str | None:
-    """The innermost active `layer_context` name, or None outside one."""
-    return _LAYER_STACK[-1] if _LAYER_STACK else None
-
 
 @contextlib.contextmanager
 def layer_context(name: str):
-    """Key every region traced inside with layer `name`.
-
-    Opens a ``jax.named_scope(name)`` so all ops of the layer carry the
-    layer name in their HLO ``op_name`` path, and pushes `name` onto the
-    layer stack that `annotate`/`current_layer` read — which is also how
-    --debug-nans and error paths name the offending layer.
-    """
-    _LAYER_STACK.append(name)
-    try:
-        with jax.named_scope(name):
-            yield
-    finally:
-        _LAYER_STACK.pop()
-
-
-def qualified(region: str) -> str:
-    """`region` prefixed with the current layer name, when one is set."""
-    layer = current_layer()
-    return f"{layer}/{region}" if layer else region
+    """Key every region traced inside with layer `name`: a
+    ``jax.named_scope(name)``, so all ops of the layer carry the layer
+    name in their HLO ``op_name`` path, and `annotate`'s regions inside it
+    read ``<layer>/<region>`` there."""
+    with jax.named_scope(name):
+        yield
 
 
 @contextlib.contextmanager
@@ -102,14 +95,64 @@ def annotate(region: str):
 
     Inside jit tracing the ``jax.named_scope`` lands `region` in the
     compiled HLO op_name metadata (nested under any `layer_context`), so
-    XLA profiles decode to plan terms; the
-    ``jax.profiler.TraceAnnotation`` additionally marks host-side
-    profiler timelines when a profiler session is active (it is a no-op
-    otherwise).
+    XLA profiles decode to plan terms.  It opens no host span: the body
+    runs while the step is traced, not while it executes.
     """
-    with jax.named_scope(region), \
-            jax.profiler.TraceAnnotation(qualified(region)):
+    with jax.named_scope(region):
         yield
+
+
+# ---------------------------------------------------------------------------
+# host spans closed when device arrays are ready
+# ---------------------------------------------------------------------------
+
+class _ReadyCloser:
+    """One daemon thread that takes (span, arrays) from a queue, waits
+    until the arrays are ready and closes the span.  Started on first
+    use."""
+
+    def __init__(self):
+        self._queue = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread = None
+
+    def close_when_ready(self, span, tree) -> None:
+        with self._lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="repro-trace-ready", daemon=True)
+                self._thread.start()
+        self._queue.put((span, tree))
+
+    def _run(self) -> None:
+        while True:
+            span, tree = self._queue.get()
+            try:
+                jax.block_until_ready(tree)
+            except Exception:  # noqa: BLE001 — the span still closes
+                log.exception("a traced span's arrays never became ready")
+            finally:
+                del tree
+                span.__exit__(None, None, None)
+
+
+_READY = _ReadyCloser()
+
+
+def span_until_ready(name: str, fn, *args):
+    """`fn(*args)` under a host profiler span `name` that opens now and
+    closes when every array `fn` returned is ready on its device(s), e.g.
+    a batch's host->device copy.  The caller never waits on the span; with
+    no profiler session it costs a TraceMe check and one queue put."""
+    span = jax.profiler.TraceAnnotation(name)
+    span.__enter__()
+    try:
+        out = fn(*args)
+    except BaseException:
+        span.__exit__(None, None, None)
+        raise
+    _READY.close_when_ready(span, out)
+    return out
 
 
 # ---------------------------------------------------------------------------

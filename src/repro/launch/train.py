@@ -36,6 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.configs import registry
+from repro.core import trace as trace_lib
 from repro.data import pipeline
 from repro.launch import compile_cache
 from repro.launch import shardings as SH
@@ -267,6 +268,29 @@ def train_step(args, opt, loss, prec, mesh, state):
         state_shardings=shardings_of(state))
 
 
+def run_step(tstep, put, state, batch, step_num: int):
+    """One training step, as `main`'s loop runs it: place the host `batch`
+    on the mesh (`put`), run the compiled step `tstep` on `state` (params,
+    optimizer state, error feedback), and read its metrics back to the
+    host, which waits for the step to finish.  Returns (new state,
+    {metric: float}).
+
+    Under a profiler session the step is a ``StepTraceAnnotation``
+    ``train.step`` (with `step_num`) holding ``train.put``,
+    ``train.dispatch`` and ``train.readback``, and ``train.h2d`` runs from
+    the start of the placement until every shard of the batch is on its
+    device (core.trace.span_until_ready): the host->device copy, which
+    ``put`` returns before finishing."""
+    with jax.profiler.StepTraceAnnotation("train.step", step_num=step_num):
+        with jax.profiler.TraceAnnotation("train.put"):
+            b = trace_lib.span_until_ready("train.h2d", put, batch)
+        with jax.profiler.TraceAnnotation("train.dispatch"):
+            *state, m = tstep(*state, b)
+        with jax.profiler.TraceAnnotation("train.readback"):
+            host = {k: float(v) for k, v in m.items()}
+    return tuple(state), host
+
+
 def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mesh1k",
@@ -438,24 +462,26 @@ def main(argv=None):
     ctx = {"tstep": tstep, "put": put, "layer_names": extras["layer_names"],
            "plan_spec": plan_record(args, cfg, extras, mesh)}
 
+    def put_noted(batch):
+        b = ctx["put"](batch)
+        ctx["batch_shardings"] = {k: v.sharding for k, v in b.items()}
+        return b
+
     def make_step():
         def run(state, step):
-            p, o, ef = state
             t_step = time.perf_counter()
-            b = ctx["put"](pf.get(step))
-            ctx["batch_shardings"] = {k: v.sharding for k, v in b.items()}
-            p, o, ef, m = ctx["tstep"](p, o, ef, b)
-            host = {k: float(v) for k, v in m.items()}    # syncs the step
+            state, host = run_step(ctx["tstep"], put_noted, state,
+                                   pf.get(step), step)
             wall = time.perf_counter() - t_step
             losses.append(host["loss"])
             if args.debug_nans:
-                debug_nan_check(step, host, p, ctx["layer_names"])
+                debug_nan_check(step, host, state[0], ctx["layer_names"])
             dt = (time.time() - t0) / (len(losses) or 1)
             mlog.log_step(step, losses[-1], step_time_s=dt,
                           samples_per_s=args.batch / dt if dt else None,
                           grad_norm=host["grad_norm"], wall_s=wall,
                           echo=step % args.log_every == 0)
-            return (p, o, ef), m
+            return state, host
         return run
 
     def remesh(survivors):

@@ -1,21 +1,23 @@
 """Plan-aware tracing & attribution (core.trace, plan.attribution_report).
 
 Single-device half here: StepTrace schema round-trip, Chrome-trace export,
-annotation wrappers (identity on values, layer-qualified region names),
-the attribution join against a compile_plan'd prediction, and the timing
+annotation wrappers (identity on values, layer-qualified region names in
+the compiled HLO, no host span), spans closed when device arrays are
+ready, the attribution join against a compile_plan'd prediction, and the timing
 helpers' new sample-returning surface.  The 4-device segmented-profiler
 acceptance (every layer attributed, sums vs whole step, annotations in
 compiled HLO) lives in tests/dist_checks.py group 'trace'.
 """
 import json
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import run_dist_group
+from conftest import host_events, run_dist_group
 from repro.core import trace as trace_lib
 from repro.core.distribution import Dist
 from repro.core.perfmodel import TPU_V5E
@@ -94,14 +96,113 @@ def test_annotate_identity_on_values():
 
 
 def test_layer_context_qualifies_regions():
-    assert trace_lib.current_layer() is None
-    assert trace_lib.qualified("reshard") == "reshard"
-    with trace_lib.layer_context("conv2_1"):
-        assert trace_lib.current_layer() == "conv2_1"
-        assert trace_lib.qualified("reshard") == "conv2_1/reshard"
-        with trace_lib.layer_context("inner"):
-            assert trace_lib.qualified("x") == "inner/x"
-    assert trace_lib.current_layer() is None
+    """A region traced inside a layer reads <layer>/<region> in the
+    compiled HLO's op_name, nested layers one path segment each."""
+    def f(x):
+        with trace_lib.layer_context("conv2_1"):
+            with trace_lib.annotate("reshard"):
+                y = jnp.sin(x)
+            with trace_lib.layer_context("inner"):
+                with trace_lib.annotate("halo_exchange"):
+                    return jnp.cos(y)
+
+    txt = jax.jit(f).lower(jnp.arange(4.0)).compile().as_text()
+    assert "conv2_1/reshard/sin" in txt
+    assert "conv2_1/inner/halo_exchange/cos" in txt
+
+
+def test_annotate_opens_no_host_span(tmp_path):
+    """annotate acts while the step is traced: under a profiler session
+    neither tracing nor running the step leaves a host event named by the
+    region, and the region still lands in the compiled HLO's op_name."""
+    def f(x):
+        with trace_lib.layer_context("conv7_7"):
+            with trace_lib.annotate("bn_collective"):
+                return jnp.tanh(x) * 3
+
+    x = jnp.arange(8.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        step = jax.jit(f)
+        txt = step.lower(x).compile().as_text()
+        step(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert "conv7_7/bn_collective/tanh" in txt
+    names = {e[0] for e in host_events(str(tmp_path))}
+    assert not {n for n in names if "bn_collective" in n}, names
+
+
+class _Span:
+    def __init__(self):
+        self.closed = threading.Event()
+        self.thread = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.thread = threading.current_thread().name
+        self.closed.set()
+
+
+class _Pending:
+    """A leaf that becomes ready when `done` is set."""
+
+    def __init__(self, fail=False):
+        self.done, self.fail = threading.Event(), fail
+
+    def block_until_ready(self):
+        assert self.done.wait(30)
+        if self.fail:
+            raise RuntimeError("transfer failed")
+        return self
+
+
+def test_span_until_ready_closes_when_the_arrays_are(monkeypatch):
+    spans = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: spans.append(_Span()) or spans[-1])
+    leaf = _Pending()
+    out = trace_lib.span_until_ready("train.h2d", lambda b: {"x": b}, leaf)
+    assert out == {"x": leaf}              # the caller never waits
+    (span,) = spans
+    assert not span.closed.wait(0.2)
+    leaf.done.set()
+    assert span.closed.wait(30)
+    assert span.thread == "repro-trace-ready"
+
+
+def test_span_until_ready_closes_on_failure(monkeypatch):
+    """A failed wait, and a placement that raises, both close the span."""
+    spans = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name: spans.append(_Span()) or spans[-1])
+    leaf = _Pending(fail=True)
+    leaf.done.set()
+    trace_lib.span_until_ready("train.h2d", lambda b: b, leaf)
+    assert spans[0].closed.wait(30)
+
+    def refuse(b):
+        raise ValueError("not divisible")
+
+    with pytest.raises(ValueError, match="not divisible"):
+        trace_lib.span_until_ready("train.h2d", refuse, leaf)
+    assert spans[1].closed.is_set()
+    # the waiter outlives the failure and serves the next span
+    ok = _Pending()
+    ok.done.set()
+    trace_lib.span_until_ready("train.h2d", lambda b: b, ok)
+    assert spans[2].closed.wait(30)
+
+
+def test_span_until_ready_keeps_one_waiter_thread():
+    xs = [jnp.full((4,), float(i)) for i in range(20)]
+    for x in xs:
+        trace_lib.span_until_ready("train.h2d", jax.device_put, x)
+    waiters = [t for t in threading.enumerate()
+               if t.name == "repro-trace-ready"]
+    assert len(waiters) == 1 and waiters[0].daemon
 
 
 def test_layer_names_in_compiled_hlo():
