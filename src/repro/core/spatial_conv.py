@@ -114,6 +114,59 @@ class ConvSharding:
             w_axis=fit_spatial_axis(w, self.w_axis, k, s, shape))
 
 
+#: Channel width whose activations run W-pair packed: two adjacent W
+#: columns of 64 channels fill the 128 lanes of the TPU's MXU and vregs.
+WPACK_C = 64
+
+
+def wpack_applies(x, sharding: ConvSharding) -> bool:
+    """Whether NHWC activation `x` runs W-pair packed: 64 channels, an even
+    W, and W not split over the mesh.  Shapes and the layout alone decide,
+    so a conv, the BN after it and the conv reading it all agree, and the
+    reshapes between them cancel in the compiled step."""
+    return (x.shape[-1] == WPACK_C and x.shape[2] % 2 == 0
+            and sharding.w_axis is None)
+
+
+def wpack(x):
+    """(N, H, W, C) -> (N, H, W/2, 2C): column 2m + a of x is packed
+    column m, channels [a*C, (a+1)*C)."""
+    n, h, w, c = x.shape
+    return x.reshape(n, h, w // 2, 2 * c)
+
+
+def wunpack(x):
+    """The inverse of `wpack`."""
+    n, h, w, c = x.shape
+    return x.reshape(n, h, 2 * w, c // 2)
+
+
+def _wpack_weights(w, stride_w: int):
+    """3-wide conv weights (K_h, 3, C, F) for a W-pair packed input.
+
+    stride_w 1: (K_h, 3, 2C, 2F), packed output, 'SAME' in packed columns:
+    ``Wp[kh, t + 1, a*C + ci, b*F + co] = w[kh, 2t + a - b + 1, ci, co]``
+    for t in {-1, 0, 1} where the tap is in [0, 2], else zero (half the
+    blocks).  stride_w 2: (K_h, 2, 2C, F), stride 1 over packed columns,
+    output j reading columns 2j, 2j + 1, 2j + 2 (SAME's (0, 1) padding):
+    ``Wp[kh, t, a*C + ci, co] = w[kh, 2t + a, ci, co]``.  Built from `w`
+    inside the traced step, so `w` gets its gradient through it.
+    """
+    zero = jnp.zeros_like(w[:, 0])
+
+    def tap(kw):
+        return w[:, kw] if 0 <= kw <= 2 else zero
+
+    if stride_w == 2:
+        return jnp.stack([jnp.concatenate([tap(2 * t + a) for a in (0, 1)],
+                                          axis=-2) for t in (0, 1)], axis=1)
+    return jnp.stack([
+        jnp.concatenate([
+            jnp.concatenate([tap(2 * t + a - b + 1) for b in (0, 1)], axis=-1)
+            for a in (0, 1)], axis=-2)
+        for t in (-1, 0, 1)], axis=1)
+
+
 def _conv_nhwc(x, w, strides, pads, backend: str = "xla",
                interior_first: bool = False):
     """Local dense conv — the per-shard compute the paper times as cuDNN.
@@ -266,8 +319,24 @@ def spatial_conv2d(x, w, *, strides=(1, 1), sharding: ConvSharding,
        (FSDP resharding at the shard_map boundary gathers them if needed).
     backend: 'xla' (default) or 'pallas' — which kernel runs the local conv
        each shard computes after its halo exchange (see _conv_nhwc).
+
+    On the XLA route a 3-wide conv of a 64-channel input whose W is even
+    and unsplit runs W-pair packed (`wpack_applies`, `_wpack_weights`):
+    the same conv, halo exchange and interior/boundary schedule, on the
+    packed array.
     """
     x = cast_to_weight_dtype(x, w)   # the repo-wide mixed-precision rule
+    if (backend == "xla" and w.shape[1] == 3 and strides[1] in (1, 2)
+            and wpack_applies(x, sharding)):
+        with jax.named_scope("conv_wpack"):
+            y = _spatial_conv2d(wpack(x), _wpack_weights(w, strides[1]),
+                                (strides[0], 1), sharding, mesh, overlap,
+                                backend)
+        return wunpack(y) if strides[1] == 1 else y
+    return _spatial_conv2d(x, w, strides, sharding, mesh, overlap, backend)
+
+
+def _spatial_conv2d(x, w, strides, sharding, mesh, overlap, backend):
     if not sharding.is_spatial:
         # pure sample parallelism: local conv, XLA batches it (paper Fig 1a).
         k_h, k_w = w.shape[0], w.shape[1]

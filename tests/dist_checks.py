@@ -3,7 +3,8 @@ process keeps a single CPU device (the 512-device env is dry-run-only).
 
 Usage:  python tests/dist_checks.py <group>
 Groups: conv | attention | ssm | models | train | compress | plan | cf |
-        spatial2d | multiaxis | memfit | overlap | trace | elastic | audit
+        spatial2d | multiaxis | memfit | overlap | trace | elastic | audit |
+        wpack
 Exits 0 on success; any assertion failure exits non-zero.
 """
 import os
@@ -1328,13 +1329,54 @@ def check_audit():
     print("audit: negative cases fire the named rules")
 
 
+def check_wpack():
+    """64-channel 3x3 convs split over a 2-device mesh: by H they run
+    W-pair packed (core.spatial_conv.wpack), by W plain; either way they
+    equal the single-device conv in value and gradients, and the packed
+    BN under the global scope equals the plain single-device BN."""
+    from repro.core.spatial_conv import spatial_conv2d, ConvSharding
+    from repro.core.spatial_norm import _batch_norm, batch_norm
+    mesh = make_mesh(data=1, model=2, devices=jax.devices()[:2])
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 16, 64))
+    w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 64, 64)) * 0.05
+    ref = oracle_conv(x, w, 1)
+    gr = jax.grad(lambda x, w: jnp.sum(oracle_conv(x, w, 1) ** 2),
+                  argnums=(0, 1))(x, w)
+    for sh, packed in ((ConvSharding(h_axis="model"), True),
+                       (ConvSharding(w_axis="model"), False)):
+        for overlap in (False, True):
+            def conv(x, w):
+                return spatial_conv2d(x, w, sharding=sh, mesh=mesh,
+                                      overlap=overlap)
+            with mesh:
+                text = jax.jit(conv).lower(x, w).as_text(debug_info=True)
+                assert ("conv_wpack" in text) == packed, (sh, overlap)
+                got = jax.jit(conv)(x, w)
+                gd = jax.jit(jax.grad(lambda x, w: jnp.sum(conv(x, w) ** 2),
+                                      argnums=(0, 1)))(x, w)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=1e-5, atol=1e-5)
+            for a, b in zip(gd, gr):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-5, atol=1e-4)
+    sh = ConvSharding(h_axis="model")
+    g = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (64,))
+    with mesh:
+        got = jax.jit(lambda x: batch_norm(x, g, g, sharding=sh, mesh=mesh,
+                                           scope="global"))(x)
+    want = _batch_norm(x, g, g, ConvSharding(), None, "local", 1e-5, fold=1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 GROUPS = {"conv": check_conv, "attention": check_attention,
           "ssm": check_ssm, "models": check_models, "train": check_train,
           "compress": check_compress, "plan": check_plan,
           "cf": check_cf, "spatial2d": check_spatial2d,
           "multiaxis": check_multiaxis, "memfit": check_memfit,
           "overlap": check_overlap, "trace": check_trace,
-          "elastic": check_elastic, "audit": check_audit}
+          "elastic": check_elastic, "audit": check_audit,
+          "wpack": check_wpack}
 
 if __name__ == "__main__":
     GROUPS[sys.argv[1]]()
