@@ -3,7 +3,11 @@
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by name:
 
-  bench/configs/<config>.json    sizes as run, source, `reduced`, `assumed`
+  bench/configs/<config>.json    sizes as run, source, `reduced`, `assumed`,
+                                 and `model`, the module below
+  bench/models/<model>.py        what only that network knows: its program
+                                 keys, batch spec, data draw, reference
+                                 loss and convolutions
   bench/traffic/<traffic>.json   batch, mesh, plan flags, pool, warm-up,
                                  and the limits of the correctness check
   bench/metrics/<metric>.py      one reducer per per-layer metric
@@ -18,6 +22,7 @@ import importlib.util
 import json
 import os
 import re
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -85,6 +90,14 @@ class Cell:
     def metric_path(self, name: str) -> str:
         return os.path.join(self.bench_dir, "metrics", f"{name}.py")
 
+    @property
+    def model_path(self) -> str:
+        return model_path(self.bench_dir, self.config["model"])
+
+
+def model_path(bench_dir: str, model: str) -> str:
+    return os.path.join(bench_dir, "models", f"{model}.py")
+
 
 def reports(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
@@ -104,6 +117,10 @@ def resolve(cell_name: str, root: str = ROOT) -> Cell:
         raise CellError(f"workload {cell_name}: no config {w['config']!r}")
     entry = configs[w["config"]]
     config = load_json(os.path.join(root, entry["file"]))
+    if "model" not in config:
+        raise CellError(f"{entry['file']}: no `model` naming "
+                        f"bench/models/<model>.py")
+    check_name(config["model"], f"config {entry['name']}: model")
     bench_dir = os.path.join(root, os.path.dirname(os.path.dirname(
         entry["file"])))
     traffic = load_json(os.path.join(bench_dir, "traffic",
@@ -120,14 +137,46 @@ def resolve(cell_name: str, root: str = ROOT) -> Cell:
         if not os.path.isfile(cell.metric_path(m["name"])):
             raise CellError(f"metric {m['name']}: no reducer at "
                             f"{cell.metric_path(m['name'])}")
+    if not os.path.isfile(cell.model_path):
+        raise CellError(f"config {entry['name']}: no model module at "
+                        f"{cell.model_path}")
+    load_model(cell)
     return cell
+
+
+def load_file(path: str, modname: str):
+    """The module at `path`, executed afresh under `modname`."""
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reducer(cell: Cell, name: str):
     """The `reduce(ctx)` function of bench/metrics/<name>.py."""
-    path = cell.metric_path(name)
     modname = "bench_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", name)
-    spec = importlib.util.spec_from_file_location(modname, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.reduce
+    return load_file(cell.metric_path(name), modname).reduce
+
+
+def model_modname(model: str) -> str:
+    return "bench_model_" + re.sub(r"[^A-Za-z0-9_]", "_", model)
+
+
+def load_model(cell: Cell):
+    """The module bench/models/<model>.py that the cell's config names,
+    kept among the process's modules, where `model_of` finds it."""
+    name = model_modname(cell.config["model"])
+    sys.modules[name] = load_file(cell.model_path, name)
+    return sys.modules[name]
+
+
+def model_of(config: dict):
+    """The module of `config['model']`: the one that resolving a cell
+    loaded, else bench/models/<model>.py beside this file.  The shared
+    code (traffic, reference, flops) asks it for what only one network
+    knows."""
+    name = model_modname(config["model"])
+    if name not in sys.modules:
+        sys.modules[name] = load_file(
+            model_path(BENCH_DIR, config["model"]), name)
+    return sys.modules[name]

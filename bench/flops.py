@@ -1,9 +1,10 @@
-"""Operations and bytes of the mesh-tangling CNN's convolutions, from shapes.
+"""Operations and bytes of a network's convolutions, from shapes.
 
 The yardstick for `mfu`, `busy_mfu` and `conv_roofline`: it counts what the
 algorithm needs, from the configuration file's sizes alone, so it does not
-move when the program does.  Per layer (NHWC, 'SAME' padding, stride 2 at
-the head of each block, a k x k filter from C to F channels):
+move when the program does.  The configuration's model module
+(bench/models/<model>.py) lists the convolutions; per layer (NHWC, 'SAME'
+padding, a k x k filter from C to F channels at stride s):
 
   forward        2 * N * Ho * Wo * k*k * C * F
   weight grad    the same count (dL/dw contracts x with dL/dy)
@@ -18,6 +19,8 @@ dL/dx.  BN, ReLU and the loss are not counted: they carry no matmul work.
 from __future__ import annotations
 
 import dataclasses
+
+import cells
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,21 +57,9 @@ class Conv:
 
 
 def convs(config: dict) -> list[Conv]:
-    """The network's convolutions in execution order, from the config
-    file's `input_hw`, `in_channels`, `widths`, `convs_per_block`,
-    `kernel`, `pred_kernel` and `n_classes`."""
-    out = []
-    c, hw = config["in_channels"], config["input_hw"]
-    for b, width in enumerate(config["widths"]):
-        for i in range(config["convs_per_block"]):
-            s = 2 if i == 0 else 1
-            out.append(Conv(f"conv{b + 1}_{i + 1}", c, width,
-                            config["kernel"], s, hw, hw))
-            hw = -(-hw // s)
-            c = width
-    out.append(Conv("pred", c, config["n_classes"], config["pred_kernel"], 1,
-                    hw, hw))
-    return out
+    """The network's convolutions in execution order, as the config's
+    model module lists them."""
+    return cells.model_of(config).convs(config)
 
 
 def forward_flops(config: dict, n: int) -> int:

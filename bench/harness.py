@@ -36,9 +36,6 @@ import traffic as traffic_lib
 
 OUT_DIR = os.path.join(cells.BENCH_DIR, "out")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# the configuration keys the program's own config must agree with
-PROGRAM_KEYS = ("input_hw", "in_channels", "convs_per_block", "widths",
-                "n_classes")
 CHECKED_STEPS = 3
 HEAD_STEPS = 3          # steps of the trace kept in bench/out/<cell>/
 
@@ -47,11 +44,11 @@ class BenchError(RuntimeError):
     """The cell cannot be run as its files describe it."""
 
 
-def program_sizes(cfg) -> dict:
-    """The PROGRAM_KEYS of a program config, as the config file writes
-    them."""
+def program_sizes(cfg, keys) -> dict:
+    """The model module's PROGRAM_KEYS `keys` of a program config, as the
+    config file writes them."""
     return {k: list(v) if isinstance(v, tuple) else v
-            for k, v in ((k, getattr(cfg, k)) for k in PROGRAM_KEYS)}
+            for k, v in ((k, getattr(cfg, k)) for k in keys)}
 
 
 def train_argv(cell: cells.Cell, seed: int) -> list[str]:
@@ -65,8 +62,9 @@ def train_argv(cell: cells.Cell, seed: int) -> list[str]:
             "--strategy", tr["strategy"], "--bn-scope", tr["bn_scope"],
             "--seed", str(seed), "--steps", str(tr["schedule_steps"]),
             "--lr", repr(cfg["optimizer"]["lr"])]
-    reduced = program_sizes(registry.get(cfg["arch"], smoke=True))
-    if all(reduced[k] == cfg[k] for k in PROGRAM_KEYS):
+    keys = cells.model_of(cfg).PROGRAM_KEYS
+    reduced = program_sizes(registry.get(cfg["arch"], smoke=True), keys)
+    if all(reduced[k] == cfg[k] for k in keys):
         argv.append("--smoke")
     return argv
 
@@ -92,7 +90,8 @@ def check_cell(cell: cells.Cell, devices) -> None:
 
 
 def check_program_config(cfg, cell: cells.Cell) -> None:
-    for k, have in program_sizes(cfg).items():
+    keys = cells.model_of(cell.config).PROGRAM_KEYS
+    for k, have in program_sizes(cfg, keys).items():
         if have != cell.config[k]:
             raise BenchError(f"{cell.name}: the program's {cfg.name} has "
                              f"{k}={have!r}, the config file "
@@ -136,10 +135,11 @@ class Program:
         self.state = train.train_state(params, opt, self.mesh)
         step = train.train_step(args, opt, loss, precision or prec,
                                 self.mesh, self.state)
+        spec = cells.model_of(cell.config).batch_spec(cell.config, tr)
         batch = {k: jax.ShapeDtypeStruct(
-            shape, jnp.float32,
+            shape, jnp.dtype(dtype),
             sharding=NamedSharding(self.mesh, extras["batch_spec"](k)))
-            for k, shape in traffic_lib.batch_shapes(cell.config, tr).items()}
+            for k, (shape, dtype) in spec.items()}
         self.compiled = step.lower(*self.state, batch).compile()
 
     def reseed(self, seed: int) -> None:

@@ -1,18 +1,13 @@
-"""Plain reference of the mesh-tangling CNN's training step.
+"""Plain reference of a training step.
 
-Written from the paper's description (arXiv:1903.06681 §VI) and the
-configuration file alone; it imports nothing of the program and takes
-nothing the program made.  Straightforward `jax.numpy` in float32, one
+Written from the paper and the configuration file alone; it imports
+nothing of the program and takes nothing the program made.  The network's
+weights and loss come from the configuration's model module
+(bench/models/<model>.py): straightforward `jax.numpy` in float32, one
 global array per tensor, convolutions at the configuration's matmul
-precision:
+precision.  Shared here:
 
-- weights: PRNGKey(seed), one split per conv in execution order, He-normal
-  (std sqrt(2 / fan_in)); BN gamma 1 and beta 0;
-- each body layer: 'SAME' conv (stride 2 at a block's head), BN in training
-  mode over the whole global batch (N, H, W) with the two-pass variance,
-  ReLU; then a 1x1 prediction conv;
-- loss: per-pixel sigmoid binary cross-entropy, mean over every logit;
-- gradients by autodiff of that loss;
+- gradients by autodiff of the model's loss;
 - SGD with momentum (mu <- m mu + g; p <- p - lr(t) mu) under a linear
   warm-up then cosine decay to `final_frac` of the base rate.
 
@@ -20,38 +15,16 @@ A run too large for one chip runs sample-parallel over the cell's chips
 under XLA's own partitioner: the batch is split over a one-axis mesh and
 everything else is replicated, so no halo or plan code is involved.
 
-`fault` plants one of the benchmark's known faults in the reference for
-reading its distance on the chip: "half_batch" takes the loss over the
-first half of the batch only; "no_halo" convolves each of `halo_parts`
-row blocks of H alone, with zero rows where a neighbour's rows belong, as
-a spatial split that skips its exchange does.
+`fault` plants one of the benchmark's known faults in the model's loss for
+reading its distance on the chip ("half_batch", "no_halo": the model
+module says what each does); `halo_parts` is the number of row blocks a
+spatial split would make.
 """
 from __future__ import annotations
 
 import numpy as np
 
-def init(seed: int, config: dict):
-    import jax
-    import jax.numpy as jnp
-
-    def he(key, k, c, f):
-        std = np.float32(np.sqrt(2.0 / (k * k * c)))
-        return jax.random.normal(key, (k, k, c, f), jnp.float32) * std
-
-    key = jax.random.PRNGKey(seed)
-    params = []
-    c = config["in_channels"]
-    for width in config["widths"]:
-        for _ in range(config["convs_per_block"]):
-            key, k1 = jax.random.split(key)
-            params.append({"conv": {"w": he(k1, config["kernel"], c, width)},
-                           "bn": {"gamma": jnp.ones((width,), jnp.float32),
-                                  "beta": jnp.zeros((width,), jnp.float32)}})
-            c = width
-    key, k1 = jax.random.split(key)
-    params.append({"conv": {"w": he(k1, config["pred_kernel"], c,
-                                    config["n_classes"])}})
-    return params
+import cells
 
 
 def lr_at(step, opt: dict, total: int):
@@ -70,45 +43,12 @@ def make_step(config: dict, schedule_steps: int, precision: str,
               fault: str | None = None, halo_parts: int = 1):
     """step(params, mu, t, batch) -> (params, mu, loss, grads), jittable."""
     import jax
-    import jax.numpy as jnp
     from jax import lax
 
     prec = {"default": lax.Precision.DEFAULT, "high": lax.Precision.HIGH,
             "highest": lax.Precision.HIGHEST}[precision]
-    eps = config["bn_eps"]
     opt = config["optimizer"]
-
-    def conv(x, w, s):
-        def one(z):
-            return lax.conv_general_dilated(
-                z, w, (s, s), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=prec)
-        if fault != "no_halo" or halo_parts == 1:
-            return one(x)
-        return jnp.concatenate(
-            [one(z) for z in jnp.split(x, halo_parts, axis=1)], axis=1)
-
-    def bn(x, gamma, beta):
-        mean = jnp.mean(x, axis=(0, 1, 2))
-        var = jnp.mean(jnp.square(x - mean), axis=(0, 1, 2))
-        return (x - mean) * lax.rsqrt(var + eps) * gamma + beta
-
-    def loss_fn(params, batch):
-        x = batch["image"]
-        li = 0
-        for _ in config["widths"]:
-            for i in range(config["convs_per_block"]):
-                lp = params[li]
-                x = conv(x, lp["conv"]["w"], 2 if i == 0 else 1)
-                x = jnp.maximum(bn(x, lp["bn"]["gamma"], lp["bn"]["beta"]),
-                                0)
-                li += 1
-        z = conv(x, params[li]["conv"]["w"], 1)
-        y = batch["label"]
-        if fault == "half_batch":
-            z, y = z[:z.shape[0] // 2], y[:y.shape[0] // 2]
-        bce = jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
-        return jnp.mean(bce)
+    loss_fn = cells.model_of(config).loss(config, prec, fault, halo_parts)
 
     def step(params, mu, t, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -147,7 +87,8 @@ class Runner:
         import jax
         import jax.numpy as jnp
 
-        params = jax.device_put(init(seed, self.config), self.rep)
+        params = jax.device_put(
+            cells.model_of(self.config).init(seed, self.config), self.rep)
         p0 = leaves_host(params)
         mu = jax.tree.map(jnp.zeros_like, params)
         losses, first_grad = [], None

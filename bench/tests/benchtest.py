@@ -56,7 +56,8 @@ def tiny_root(tmp, arch="mesh1k", data=1, model=1, name="tiny"):
     bench["workloads"].append({"name": name, "config": name,
                                "traffic": name, "chips": data * model,
                                "why": "CPU test"})
-    for real in ("configs", "traffic"):
+    for real in ("configs", "traffic", "models"):
+        os.makedirs(os.path.join(root, "bench", real), exist_ok=True)
         for f in os.listdir(os.path.join(BENCH, real)):
             dst = os.path.join(root, "bench", real, f)
             if not os.path.exists(dst):

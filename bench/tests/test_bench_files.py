@@ -127,7 +127,7 @@ def test_missing_files_are_named(tmp_path):
 
 def test_importing_the_benchmark_touches_no_jax():
     mods = ["cells", "check", "devtrace", "flops", "harness", "limits",
-            "reference", "run", "traffic"]
+            "reference", "run", "traffic", "models.meshnet"]
     code = (f"import sys; sys.path.insert(0, {BENCH!r}); "
             + "; ".join(f"import {m}" for m in mods)
             + "; print(sorted(m for m in sys.modules "

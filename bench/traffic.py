@@ -1,11 +1,9 @@
 """The one traffic generator: a pool of training batches from the seed.
 
 A traffic file (bench/traffic/<name>.json) gives the global batch and the
-pool size; the configuration gives the sample shape.  Samples follow the
-program's synthetic mesh-tangling data (the paper trained its speed runs on
-synthetic data, §VI): an `in_channels`-deep `input_hw`^2 image of standard
-normals, and a per-pixel tangle mask on the prediction grid that is 1 with
-probability `label_positive_rate`.  Every batch of the pool holds other
+pool size; the configuration's model module (bench/models/<model>.py) gives
+each batch key's shape and dtype and draws one batch from a key, with what
+else the traffic file states for it.  Every batch of the pool holds other
 rows, so the first steps, which the reference follows, all differ.
 
 The pool is drawn on the device in one compiled call per batch and held in
@@ -17,6 +15,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import cells
+
 
 def data_key(seed: int) -> np.ndarray:
     """A raw threefry key (uint32[2]) for the data, derived from `seed` and
@@ -25,28 +25,13 @@ def data_key(seed: int) -> np.ndarray:
     return ss.generate_state(2, dtype=np.uint32)
 
 
-def batch_shapes(config: dict, traffic: dict) -> dict:
-    n, hw = traffic["batch"], config["input_hw"]
-    out_hw = hw // 2 ** len(config["widths"])
-    return {"image": (n, hw, hw, config["in_channels"]),
-            "label": (n, out_hw, out_hw, config["n_classes"])}
-
-
 def batch_pool(config: dict, traffic: dict, seed: int) -> list[dict]:
-    """`traffic['pool']` batches of numpy float32 arrays."""
+    """`traffic['pool']` batches of numpy arrays."""
     import jax
     import jax.numpy as jnp
 
-    shapes = batch_shapes(config, traffic)
-    rate = traffic["label_positive_rate"]
-
-    @jax.jit
-    def draw(key):
-        ki, kl = jax.random.split(key)
-        return {"image": jax.random.normal(ki, shapes["image"], jnp.float32),
-                "label": jax.random.bernoulli(kl, rate, shapes["label"])
-                .astype(jnp.float32)}
-
+    model = cells.model_of(config)
+    draw = jax.jit(lambda key: model.draw(key, config, traffic))
     keys = jax.random.split(jnp.asarray(data_key(seed)), traffic["pool"])
     pool = []
     for k in keys:
