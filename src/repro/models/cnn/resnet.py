@@ -14,6 +14,13 @@ GPUs).
 
 `resnet_graph` exports the branchy layer DAG consumed by the strategy
 optimizer's longest-path-first pass (paper §V-C).
+
+Every plan layer runs under ``trace.layer_context(name)``, as in
+models.cnn.meshnet, so the compiled HLO's ``op_name`` carries the layer:
+``conv1`` (stem conv, BN, ReLU), ``pool1``, ``res{s}{b}_branch2a/2b/2c``
+and ``_branch1`` (conv and the BN after it, and the ReLU where there is
+one), ``res{s}{b}`` (the residual add and its ReLU, Caffe's name for the
+sum), ``pool5`` (global average pool) and ``fc`` (the head).
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import networkx as nx
 
+from repro.core import trace as trace_lib
 from repro.core.perfmodel import ConvLayer
 from repro.core.spatial_conv import ConvSharding
 from repro.models.cnn import layers as L
@@ -92,16 +100,21 @@ def _bottleneck_apply(p, x, *, pre, stride, plan, mesh, scope, overlap):
         shb = plan.sharding(name).fit(z.shape[1], z.shape[2], 1, 1, mesh)
         return L.bn_apply(pp, z, sharding=shb, mesh=mesh, scope=scope)
 
-    y = conv(pre + "2a", p["conv1"], x, 1)
-    y = L.relu(bn(pre + "2a", p["bn1"], y))
-    y = conv(pre + "2b", p["conv2"], y, stride)
-    y = L.relu(bn(pre + "2b", p["bn2"], y))
-    y = conv(pre + "2c", p["conv3"], y, 1)
-    y = bn(pre + "2c", p["bn3"], y)
+    with trace_lib.layer_context(pre + "2a"):
+        y = conv(pre + "2a", p["conv1"], x, 1)
+        y = L.relu(bn(pre + "2a", p["bn1"], y))
+    with trace_lib.layer_context(pre + "2b"):
+        y = conv(pre + "2b", p["conv2"], y, stride)
+        y = L.relu(bn(pre + "2b", p["bn2"], y))
+    with trace_lib.layer_context(pre + "2c"):
+        y = conv(pre + "2c", p["conv3"], y, 1)
+        y = bn(pre + "2c", p["bn3"], y)
     if "proj" in p:
-        x = conv(pre + "1", p["proj"], x, stride)
-        x = bn(pre + "1", p["bn_proj"], x)
-    return L.relu(x + y)
+        with trace_lib.layer_context(pre + "1"):
+            x = conv(pre + "1", p["proj"], x, stride)
+            x = bn(pre + "1", p["bn_proj"], x)
+    with trace_lib.layer_context(pre.removesuffix("_branch")):
+        return L.relu(x + y)
 
 
 def apply(params, x, cfg: ResNetConfig = RESNET50, plan=None, mesh=None,
@@ -113,16 +126,18 @@ def apply(params, x, cfg: ResNetConfig = RESNET50, plan=None, mesh=None,
     """
     from repro.core.plan import NetworkPlan
     plan = NetworkPlan.of(plan)
-    x = plan.reshard(x, "conv1", mesh)
-    x = L.conv_apply(params["conv1"], x, stride=2,
-                     sharding=plan.sharding("conv1"), mesh=mesh,
-                     overlap=overlap)
-    shb = plan.sharding("conv1").fit(x.shape[1], x.shape[2], 1, 1, mesh)
-    x = L.relu(L.bn_apply(params["bn1"], x, sharding=shb, mesh=mesh,
-                          scope=cfg.bn_scope))
-    x = plan.reshard(x, "pool1", mesh)
-    x = L.max_pool(x, window=3, stride=2, sharding=plan.sharding("pool1"),
-                   mesh=mesh)
+    with trace_lib.layer_context("conv1"):
+        x = plan.reshard(x, "conv1", mesh)
+        x = L.conv_apply(params["conv1"], x, stride=2,
+                         sharding=plan.sharding("conv1"), mesh=mesh,
+                         overlap=overlap)
+        shb = plan.sharding("conv1").fit(x.shape[1], x.shape[2], 1, 1, mesh)
+        x = L.relu(L.bn_apply(params["bn1"], x, sharding=shb, mesh=mesh,
+                              scope=cfg.bn_scope))
+    with trace_lib.layer_context("pool1"):
+        x = plan.reshard(x, "pool1", mesh)
+        x = L.max_pool(x, window=3, stride=2,
+                       sharding=plan.sharding("pool1"), mesh=mesh)
     bi = 0
     last = "pool1"
     for s, (n_blocks, width) in enumerate(zip(cfg.stages, cfg.widths)):
@@ -134,9 +149,11 @@ def apply(params, x, cfg: ResNetConfig = RESNET50, plan=None, mesh=None,
                                   scope=cfg.bn_scope, overlap=overlap)
             last = pre + "2c"
             bi += 1
-    x = L.global_avg_pool(x, sharding=plan.sharding(last).fit(
-        x.shape[1], x.shape[2], 1, 1, mesh), mesh=mesh)
-    return L.dense_apply(params["head"], x)
+    with trace_lib.layer_context("pool5"):
+        x = L.global_avg_pool(x, sharding=plan.sharding(last).fit(
+            x.shape[1], x.shape[2], 1, 1, mesh), mesh=mesh)
+    with trace_lib.layer_context("fc"):
+        return L.dense_apply(params["head"], x)
 
 
 def loss_fn(params, batch, cfg: ResNetConfig = RESNET50, plan=None,
