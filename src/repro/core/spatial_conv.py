@@ -386,24 +386,16 @@ def _local_pool(x, *, window, strides, sharding: ConvSharding, mesh_shape,
 
 
 def _pool_windows(x, window, strides, pads, kind):
-    """Pooling via stacked shifted slices + reduce over the window axis —
-    fully reverse-differentiable (reduce_window's max transpose is not
-    supported under shard_map's manual axes)."""
-    k_h, k_w = window
-    s_h, s_w = strides
-    edge = jnp.asarray(float("-inf") if kind == "max" else 0.0, x.dtype)
-    x = jnp.pad(x, pads, constant_values=edge)
-    h_out = (x.shape[1] - k_h) // s_h + 1
-    w_out = (x.shape[2] - k_w) // s_w + 1
-    taps = []
-    for i in range(k_h):
-        for j in range(k_w):
-            taps.append(x[:, i:i + h_out * s_h:s_h,
-                          j:j + w_out * s_w:s_w, :])
-    stack = jnp.stack(taps, axis=-1)
+    """Pool the local block padded by `pads` (-inf for max, zeros for avg)
+    with `lax.reduce_window`, which reads each window in place.  Its
+    gradient is XLA's `select_and_scatter` for max, which routes each
+    window's cotangent to its first tap equal to the max, and a padded
+    `reduce_window` sum for avg: neither gathers the window taps."""
+    dims, steps = (1, *window, 1), (1, *strides, 1)
     if kind == "max":
-        return jnp.max(stack, axis=-1)
-    return jnp.sum(stack, axis=-1) / (k_h * k_w)
+        return lax.reduce_window(x, -jnp.inf, lax.max, dims, steps, pads)
+    y = lax.reduce_window(x, 0.0, lax.add, dims, steps, pads)
+    return y / (window[0] * window[1])
 
 
 def spatial_pool(x, *, window=(3, 3), strides=(2, 2),
@@ -413,19 +405,19 @@ def spatial_pool(x, *, window=(3, 3), strides=(2, 2),
     Max pooling fills the *global-edge* halo with -inf (not the zeros that
     ppermute produces) so edge windows match single-device 'SAME' semantics.
     Avg pooling uses count_include_pad=True (zero pad), matching the oracle in
-    models/cnn/layers.py.
+    models/cnn/layers.py.  Every op of the pool, its gradient's included,
+    carries the `max_pool` (or `avg_pool`) scope in its HLO `op_name`.
     """
-    if not sharding.is_spatial:
-        k_h, k_w = window
-        s_h, s_w = strides
-        return _pool_windows(
-            x, window, strides,
-            ((0, 0), same_pads(k_h, s_h), same_pads(k_w, s_w), (0, 0)),
-            kind)
+    with jax.named_scope(f"{kind}_pool"):
+        if not sharding.is_spatial:
+            pads = lax.padtype_to_pads(x.shape[1:3], window, strides, "SAME")
+            return _pool_windows(x, window, strides,
+                                 ((0, 0), *pads, (0, 0)), kind)
 
-    mesh = mesh or jax.sharding.get_abstract_mesh()
-    fn = functools.partial(_local_pool, window=window, strides=strides,
-                           sharding=sharding, mesh_shape=dict(mesh.shape),
-                           kind=kind)
-    spec = sharding.x_spec()
-    return shard_map(fn, mesh=mesh, in_specs=(spec,), out_specs=spec)(x)
+        mesh = mesh or jax.sharding.get_abstract_mesh()
+        fn = functools.partial(_local_pool, window=window, strides=strides,
+                               sharding=sharding,
+                               mesh_shape=dict(mesh.shape), kind=kind)
+        spec = sharding.x_spec()
+        return shard_map(fn, mesh=mesh, in_specs=(spec,),
+                         out_specs=spec)(x)

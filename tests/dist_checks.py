@@ -578,6 +578,21 @@ def check_spatial2d():
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=1e-6, atol=1e-6)
 
+    # the max pool's gradient under H, W and H x W splits: ReLU'd input
+    # ties at 0, and the halo keeps each window's first tap first
+    x = jnp.maximum(jax.random.normal(key, (2, 16, 16, 3)), 0)
+
+    def pool_loss(sh, mesh=None):
+        return lambda x: jnp.sum(jnp.sin(spatial_pool(
+            x, window=(3, 3), strides=(2, 2), sharding=sh, mesh=mesh)))
+
+    ref = jax.grad(pool_loss(ConvSharding()))(x)
+    for sh in (ConvSharding(batch_axes=("data",), h_axis="model"), shw, sh2):
+        with mesh:
+            got = jax.jit(jax.grad(pool_loss(sh, mesh)))(x)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-6)
+
     # a compiled plan whose dists shard W — through the full model stack
     from repro.core import plan as plan_lib
     from repro.core.distribution import Dist

@@ -34,3 +34,10 @@ def test_the_compiled_step_names_every_layer():
     # the backward pass is named too
     assert any(re.search(r"transpose\(jvp\(res5a_branch2b\)\)", o)
                for o in op_names)
+    # the max pool's own scope (core.spatial_conv.spatial_pool), on its
+    # forward and its backward ops, inside pool1 alone
+    pool = [o for o in op_names if "/max_pool/" in o]
+    assert any(o.startswith("jit(step)/jvp(pool1)/") for o in pool)
+    assert any(o.startswith("jit(step)/transpose(jvp(pool1))/")
+               for o in pool)
+    assert all("pool1" in o for o in pool)
